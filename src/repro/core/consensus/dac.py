@@ -42,8 +42,9 @@ def dac(w0: jax.Array, A: jax.Array, iters: int, eps: float | None = None):
     P = perron(A, eps).astype(w0.dtype)
 
     def body(w, _):
-        w_next = P @ w
-        return w_next, _maximin_residual(w_next)
+        with jax.named_scope("dac/round"):
+            w_next = P @ w
+            return w_next, _maximin_residual(w_next)
 
     return jax.lax.scan(body, w0, None, length=iters)
 
@@ -77,11 +78,12 @@ def dac_time_varying(w0: jax.Array, A_seq: jax.Array, eps: float):
 
     Returns (w_final, residual trajectory)."""
     def body(w, A_t):
-        M = A_t.shape[0]
-        P_t = jnp.eye(M, dtype=w.dtype) - eps * (
-            jnp.diag(jnp.sum(A_t, axis=1)) - A_t).astype(w.dtype)
-        w_next = P_t @ w
-        return w_next, _maximin_residual(w_next)
+        with jax.named_scope("dac/round"):
+            M = A_t.shape[0]
+            P_t = jnp.eye(M, dtype=w.dtype) - eps * (
+                jnp.diag(jnp.sum(A_t, axis=1)) - A_t).astype(w.dtype)
+            w_next = P_t @ w
+            return w_next, _maximin_residual(w_next)
 
     return jax.lax.scan(body, w0, A_seq)
 
@@ -107,18 +109,20 @@ def dac_sharded(w_local: jax.Array, axis_name: str, iters: int,
     perm_bwd = [(i, (i - 1) % M) for i in range(M)]
 
     def body(w, _):
-        left = jax.lax.ppermute(w, axis_name, perm_fwd)
-        right = jax.lax.ppermute(w, axis_name, perm_bwd)
-        nbr = (left - w) + (right - w)
-        if M == 2:
-            # On a 2-ring the forward and backward permutations deliver the
-            # SAME single neighbor; counting it twice doubles the consensus
-            # gain vs the simulated single-edge graph. Halve to match.
-            nbr = 0.5 * nbr
-        w_next = w + eps * nbr
-        res = dac_sharded_residual(w_next, axis_name) if with_residuals \
-            else None
-        return w_next, res
+        with jax.named_scope("dac/round"):
+            left = jax.lax.ppermute(w, axis_name, perm_fwd)
+            right = jax.lax.ppermute(w, axis_name, perm_bwd)
+            nbr = (left - w) + (right - w)
+            if M == 2:
+                # On a 2-ring the forward and backward permutations deliver
+                # the SAME single neighbor; counting it twice doubles the
+                # consensus gain vs the simulated single-edge graph. Halve
+                # to match.
+                nbr = 0.5 * nbr
+            w_next = w + eps * nbr
+            res = dac_sharded_residual(w_next, axis_name) \
+                if with_residuals else None
+            return w_next, res
 
     w, resids = jax.lax.scan(body, w_local, None, length=iters)
     return (w, resids) if with_residuals else w

@@ -80,16 +80,17 @@ def dac_masked(w0: jax.Array, A: jax.Array, alive_seq: jax.Array,
         else (alive_seq, edge_seq.astype(w0.dtype))
 
     def body(w, x):
-        alive_t = x[0]
-        A_t = A * alive_t[:, None] * alive_t[None, :]
-        if edge_seq is not None:
-            A_t = A_t * x[1]
-        d_t = jnp.sum(A_t, axis=1)
-        w_next = w + eps * (A_t @ w - d_t[:, None] * w) if w.ndim == 2 \
-            else w + eps * (A_t @ w - d_t * w)
-        keep = alive_t[:, None] > 0 if w.ndim == 2 else alive_t > 0
-        w_next = jnp.where(keep, w_next, w)
-        return w_next, _masked_maximin(w_next, alive_t)
+        with jax.named_scope("dac/round"):
+            alive_t = x[0]
+            A_t = A * alive_t[:, None] * alive_t[None, :]
+            if edge_seq is not None:
+                A_t = A_t * x[1]
+            d_t = jnp.sum(A_t, axis=1)
+            w_next = w + eps * (A_t @ w - d_t[:, None] * w) \
+                if w.ndim == 2 else w + eps * (A_t @ w - d_t * w)
+            keep = alive_t[:, None] > 0 if w.ndim == 2 else alive_t > 0
+            w_next = jnp.where(keep, w_next, w)
+            return w_next, _masked_maximin(w_next, alive_t)
 
     return jax.lax.scan(body, w0, xs)
 

@@ -44,9 +44,13 @@ def nll_from_cov(C: jax.Array, y: jax.Array) -> jax.Array:
 def inner_from_cov(C: jax.Array, y: jax.Array) -> jax.Array:
     """inner = C^-1 - alpha alpha^T, the trace-identity operand of eq. 4 —
     shared by `nll_grad_analytic` and the fused cached path."""
+    return inner_from_factor(jnp.linalg.cholesky(C), y)
+
+
+def inner_from_factor(L: jax.Array, y: jax.Array) -> jax.Array:
+    """`inner_from_cov` from C's Cholesky factor L: the explicit inverse."""
     n = y.shape[0]
-    L = jnp.linalg.cholesky(C)
-    Cinv = jax.scipy.linalg.cho_solve((L, True), jnp.eye(n, dtype=C.dtype))
+    Cinv = jax.scipy.linalg.cho_solve((L, True), jnp.eye(n, dtype=L.dtype))
     alpha = Cinv @ y
     return Cinv - jnp.outer(alpha, alpha)
 

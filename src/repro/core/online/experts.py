@@ -243,23 +243,30 @@ def _append_one(log_theta, jitter, Xw, yw, L, slot, x, y):
 
 
 def _observe_one(log_theta, jitter, Xw, yw, L, count, x, y):
+    # device scopes: the evict scope holds the cond too (vmapped, it becomes
+    # a select over the whole factor)
     full = count >= Xw.shape[0]
-    Xw, yw, L = jax.lax.cond(
-        full,
-        lambda a: _evict_oldest_shift(log_theta, jitter, *a),
-        lambda a: a, (Xw, yw, L))
+    with jax.named_scope("online/evict"):
+        Xw, yw, L = jax.lax.cond(
+            full,
+            lambda a: _evict_oldest_shift(log_theta, jitter, *a),
+            lambda a: a, (Xw, yw, L))
     count = jnp.where(full, count - 1, count)
-    Xw, yw, L = _append_one(log_theta, jitter, Xw, yw, L, count, x, y)
-    alpha = _cho_solve(L, yw)
+    with jax.named_scope("online/append"):
+        Xw, yw, L = _append_one(log_theta, jitter, Xw, yw, L, count, x, y)
+    with jax.named_scope("online/alpha"):
+        alpha = _cho_solve(L, yw)
     return Xw, yw, L, alpha, count + 1
 
 
 def _evict_one(log_theta, jitter, Xw, yw, L, count):
-    Xw, yw, L = jax.lax.cond(
-        count > 0,
-        lambda a: _evict_oldest_shift(log_theta, jitter, *a),
-        lambda a: a, (Xw, yw, L))
-    alpha = _cho_solve(L, yw)
+    with jax.named_scope("online/evict"):
+        Xw, yw, L = jax.lax.cond(
+            count > 0,
+            lambda a: _evict_oldest_shift(log_theta, jitter, *a),
+            lambda a: a, (Xw, yw, L))
+    with jax.named_scope("online/alpha"):
+        alpha = _cho_solve(L, yw)
     return Xw, yw, L, alpha, jnp.maximum(count - 1, 0)
 
 

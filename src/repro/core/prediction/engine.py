@@ -216,25 +216,30 @@ class PredictionEngine:
         """Local expert moments — isinstance dispatch is what lets every
         PoE/BCM/CBNN aggregation serve dense and sparse fleets from the
         same engine (the shapes differ, the (M, Nt) contract does not)."""
-        if isinstance(f, SparseExperts):
-            return sparse_moments_cached(f.log_theta, f.Z, f.Lmm, f.LB, f.c,
-                                         Xq, stream_mean=self.stream_mean)
-        return local_moments_cached(f.log_theta, f.Xp, f.L, f.alpha, Xq,
-                                    stream_mean=self.stream_mean)
+        with jax.named_scope("serve/local_moments"):
+            if isinstance(f, SparseExperts):
+                return sparse_moments_cached(
+                    f.log_theta, f.Z, f.Lmm, f.LB, f.c, Xq,
+                    stream_mean=self.stream_mean)
+            return local_moments_cached(f.log_theta, f.Xp, f.L, f.alpha, Xq,
+                                        stream_mean=self.stream_mean)
 
     def _mask(self, f, Xq):
         """CBNN participation mask (eq. 39) from dense or sparse factors —
         both score forms equal sigma_f^2 - var_i, so eta_nn thresholds are
         directly comparable across expert representations."""
-        if isinstance(f, SparseExperts):
-            return _mask_from_scores(
-                sparse_scores(f.log_theta, f.Z, f.Lmm, f.LB, Xq),
-                self.eta_nn)
-        return cbnn_mask_cached(f.log_theta, f.Xp, f.L, Xq, self.eta_nn)[0]
+        with jax.named_scope("serve/local_moments"):
+            if isinstance(f, SparseExperts):
+                return _mask_from_scores(
+                    sparse_scores(f.log_theta, f.Z, f.Lmm, f.LB, Xq),
+                    self.eta_nn)
+            return cbnn_mask_cached(f.log_theta, f.Xp, f.L, Xq,
+                                    self.eta_nn)[0]
 
     def _terms(self, f: FittedExperts, Xq):
-        return npae_terms_cached(f.log_theta, f.Xp, f.L, f.alpha, Xq,
-                                 Kcross=f.Kcross)
+        with jax.named_scope("serve/local_moments"):
+            return npae_terms_cached(f.log_theta, f.Xp, f.L, f.alpha, Xq,
+                                     Kcross=f.Kcross)
 
     def _tile(self, method: str, f, fa, fc, Xq, chaos=None):
         A, pv = self.A, f.prior_var
@@ -270,9 +275,10 @@ class PredictionEngine:
             mu, var = self._moments(f, Xq)
             if chaos is not None:
                 mu, var, mask = degrade(mu, var, mask)
-            mean, v, info = _DAC_CORES[base](mu, var, pv, A,
-                                             iters=self.dac_iters, mask=mask,
-                                             dac_fn=dac_fn)
+            with jax.named_scope("serve/aggregate"):
+                mean, v, info = _DAC_CORES[base](
+                    mu, var, pv, A, iters=self.dac_iters, mask=mask,
+                    dac_fn=dac_fn)
             red["dac_residual"] = info["dac_residuals"][-1]
             if self.diagnostics:
                 # full per-round trajectory; max-reduced elementwise over
@@ -285,9 +291,10 @@ class PredictionEngine:
                 # the communication expert is a serving-host dataset, not a
                 # fleet member — only the augmented experts take faults
                 mu_a, var_a, mask = degrade(mu_a, var_a, mask)
-            mean, v, info = dec_grbcm_from_moments(
-                mu_a, var_a, mu_c[0], var_c[0], A, iters=self.dac_iters,
-                mask=mask, dac_fn=dac_fn)
+            with jax.named_scope("serve/aggregate"):
+                mean, v, info = dec_grbcm_from_moments(
+                    mu_a, var_a, mu_c[0], var_c[0], A, iters=self.dac_iters,
+                    mask=mask, dac_fn=dac_fn)
             red["dac_residual"] = info["dac_residuals"][-1]
             if self.diagnostics:
                 red["dac_residuals"] = info["dac_residuals"]
@@ -297,9 +304,10 @@ class PredictionEngine:
             if chaos is not None:
                 mu, _, mask = degrade(mu, jnp.zeros_like(mu) + pv, mask)
                 A_dale, readout = chaos["A_live"], chaos["readout"]
-            mean, v, info = dec_nn_npae_from_terms(
-                mask, mu, kA, CA, pv, A_dale, dale_iters=self.dale_iters,
-                jitter=self.npae_jitter, readout=readout)
+            with jax.named_scope("serve/aggregate"):
+                mean, v, info = dec_nn_npae_from_terms(
+                    mask, mu, kA, CA, pv, A_dale, dale_iters=self.dale_iters,
+                    jitter=self.npae_jitter, readout=readout)
             red["dale_residual"] = info["dale_residual"]
         elif method in ("npae", "npae_star"):
             mu, kA, CA = self._terms(f, Xq)
@@ -308,11 +316,12 @@ class PredictionEngine:
             core = (dec_npae_from_terms if method == "npae"
                     else partial(dec_npae_star_from_terms,
                                  pm_iters=self.pm_iters))
-            mean, v, info = core(mu, kA, CA, pv, A, jor_iters=self.jor_iters,
-                                 dac_iters=self.dac_iters,
-                                 jitter=self.npae_jitter,
-                                 with_residuals=self.diagnostics,
-                                 mask=mask, dac_fn=dac_fn)
+            with jax.named_scope("serve/aggregate"):
+                mean, v, info = core(
+                    mu, kA, CA, pv, A, jor_iters=self.jor_iters,
+                    dac_iters=self.dac_iters, jitter=self.npae_jitter,
+                    with_residuals=self.diagnostics, mask=mask,
+                    dac_fn=dac_fn)
             red["dac_residual"] = info["dac_residuals"][-1]
             red["jor_residual"] = info["jor_residual"]
             if self.diagnostics:
@@ -321,22 +330,27 @@ class PredictionEngine:
         elif method == "npae_sparse":
             # low-rank NPAE: cross-covariance through the pseudo-points,
             # solved by the SAME aggregation core as the exact family
-            mu, kA, CA = npae_terms_lowrank(f.log_theta, f.Z, f.Lmm, f.LB,
-                                            f.c, Xq)
-            mean, v = agg.npae(mu, kA, CA, pv, jitter=self.npae_jitter)
+            with jax.named_scope("serve/local_moments"):
+                mu, kA, CA = npae_terms_lowrank(f.log_theta, f.Z, f.Lmm,
+                                                f.LB, f.c, Xq)
+            with jax.named_scope("serve/aggregate"):
+                mean, v = agg.npae(mu, kA, CA, pv, jitter=self.npae_jitter)
         elif method == "cen_npae":
             mu, kA, CA = self._terms(f, Xq)
-            mean, v = agg.npae(mu, kA, CA, pv)
+            with jax.named_scope("serve/aggregate"):
+                mean, v = agg.npae(mu, kA, CA, pv)
         elif method == "cen_grbcm":
             mu_a, var_a = self._moments(fa, Xq)
             mu_c, var_c = self._moments(fc, Xq)
-            mean, v = agg.grbcm(mu_a, var_a, mu_c[0], var_c[0])
+            with jax.named_scope("serve/aggregate"):
+                mean, v = agg.grbcm(mu_a, var_a, mu_c[0], var_c[0])
         elif method in ("cen_poe", "cen_gpoe", "cen_bcm", "cen_rbcm"):
             mu, var = self._moments(f, Xq)
             fn = getattr(agg, method[4:])
             args = (mu, var, pv) if method in ("cen_bcm", "cen_rbcm") \
                 else (mu, var)
-            mean, v = fn(*args)
+            with jax.named_scope("serve/aggregate"):
+                mean, v = fn(*args)
         else:
             raise ValueError(f"unknown prediction method {method!r}")
 

@@ -127,11 +127,12 @@ def dec_apx_update(thetas, p, grads, nbr_sum, deg, rho, kappa):
     thetas (M, K), p (M, K), grads = grad L_i(theta_i) (M, K),
     nbr_sum = sum_{j in N_i} theta_j (M, K), deg (M,).
     """
-    degc = deg[:, None]
-    p_next = p + rho * (degc * thetas - nbr_sum)                    # (34a)
-    thetas_next = (rho * nbr_sum - grads
-                   + (kappa + degc * rho) * thetas - p_next) \
-        / (kappa + 2.0 * degc * rho)                                # (34b)
+    with jax.named_scope("admm/update"):
+        degc = deg[:, None]
+        p_next = p + rho * (degc * thetas - nbr_sum)                # (34a)
+        thetas_next = (rho * nbr_sum - grads
+                       + (kappa + degc * rho) * thetas - p_next) \
+            / (kappa + 2.0 * degc * rho)                            # (34b)
     return thetas_next, p_next
 
 
@@ -162,7 +163,8 @@ def train_dec_apx_gp(log_theta0, Xp, yp, A, rho: float = 500.0,
 
     def body(carry, _):
         thetas, p = carry
-        nbr_sum = Af @ thetas
+        with jax.named_scope("admm/update"):
+            nbr_sum = Af @ thetas
         grads = fleet_grads(thetas, aux)
         thetas_next, p = dec_apx_update(thetas, p, grads, nbr_sum, deg,
                                         rho, kappa)

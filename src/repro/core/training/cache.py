@@ -37,7 +37,7 @@ import jax.numpy as jnp
 
 from ...kernels.ops import nll_grad_fused
 from ..gp.kernel import diff2_stack, unpack
-from ..gp.nll import (effective_jitter, inner_from_cov, nll, nll_from_cov)
+from ..gp.nll import effective_jitter, inner_from_factor, nll, nll_from_cov
 
 
 class TrainingCache(NamedTuple):
@@ -87,12 +87,19 @@ def nll_grad_cached(log_theta, d2u, y, jitter: float = 1e-8,
 
     Per-iteration cost: one Cholesky + one triangular pair for the explicit
     inverse + the single fused contraction. No autodiff, no geometry
-    recompute, no (D+2, N, N) stack.
+    recompute, no (D+2, N, N) stack. Each part runs under its own device
+    scope (`admm/nll_grad` holds the covariance rebuild and the
+    contraction).
     """
-    C, K = cov_from_cache(log_theta, d2u, jitter)
-    inner = inner_from_cov(C, y)
-    return nll_grad_fused(log_theta, d2u, inner, K=K, use_pallas=use_pallas,
-                          interpret=interpret)
+    with jax.named_scope("admm/nll_grad"):
+        C, K = cov_from_cache(log_theta, d2u, jitter)
+    with jax.named_scope("admm/cholesky"):
+        L = jnp.linalg.cholesky(C)
+    with jax.named_scope("admm/inverse"):
+        inner = inner_from_factor(L, y)
+    with jax.named_scope("admm/nll_grad"):
+        return nll_grad_fused(log_theta, d2u, inner, K=K,
+                              use_pallas=use_pallas, interpret=interpret)
 
 
 def local_nll(log_theta, aux, jitter: float = 1e-8):

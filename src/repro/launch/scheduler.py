@@ -58,9 +58,16 @@ counter mirrors into the metrics registry as a `tenant`-labeled series
 bounded histogram sketch instead of a sample deque, and each request
 carries a `Span` through queue -> pack -> dispatch -> device -> stitch
 whose per-stage timings land in gp_request_stage_seconds and — when a
-`span_log` is configured — in a JSONL event per request. All timing uses
-`time.perf_counter()` (monotonic, highest resolution); disabling the
-registry reduces every hook to an early-return.
+`span_log` is configured — in a JSONL event per request. Each request's
+queue time is split by cause (`queue_split_ms`): `behind_slot`, while one
+of its tenant's slots was in flight (the tenant's in-flight clock), and
+`idle`, the batching timer and the worker's lag (gp_queue_idle_seconds).
+With a `span_log`, every dispatched slot also emits one `slot` event whose
+stages (pack, dispatch, device_wait, fetch, deliver) tile its host wall
+time, and the worker marks the same stages in the profiler's trace with
+static `scheduler/*` annotations. All timing uses `time.perf_counter()`
+(monotonic, highest resolution); disabling the registry reduces every
+hook to an early-return.
 
 `GPFleet.to_server()` returns a one-tenant scheduler; `launch.frontdoor.
 FrontDoor` is the v1-compatible shim over the same machinery.
@@ -77,6 +84,7 @@ from concurrent.futures import Future
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..obs import Histogram, MetricsRegistry, Span, SpanLog, default_registry
 
@@ -228,6 +236,9 @@ class TenantStats:
         self._mirror_stage = reg.histogram(
             "gp_request_stage_seconds", "per-stage request time "
             "(queue|pack|dispatch|device|stitch)")
+        self._mirror_idle = reg.histogram(
+            "gp_queue_idle_seconds", "queue time with no slot of the "
+            "tenant in flight (batching timer and worker lag)")
         self._gauge_pad = reg.gauge(
             "gp_padding_fraction", "pad rows / dispatched rows")
 
@@ -251,6 +262,9 @@ class TenantStats:
     def record_stages(self, stages: dict[str, float]):
         for stage, dt in stages.items():
             self._mirror_stage.observe(dt, tenant=self.tenant, stage=stage)
+
+    def record_queue_idle(self, seconds: float):
+        self._mirror_idle.observe(seconds, tenant=self.tenant)
 
     def update_gauges(self):
         self._gauge_pad.set(self.padding_fraction, tenant=self.tenant)
@@ -329,9 +343,12 @@ class TenantStats:
 
 class _Request:
     """One in-flight request; `off` rows are already reserved into slots,
-    `parts` holds the per-slot answer slices until all `n` rows return."""
+    `parts` holds the per-slot (mean, var, slot id) answer slices until all
+    `n` rows return. With a span, `q_clock` is the tenant's in-flight clock
+    when the request last entered the queue and `behind_s` its queue time
+    spent behind an in-flight slot so far."""
     __slots__ = ("Xq", "n", "fut", "priority", "deadline", "arrival", "seq",
-                 "off", "parts", "lapsed", "span")
+                 "off", "parts", "lapsed", "span", "q_clock", "behind_s")
 
     def __init__(self, Xq, fut, priority, deadline, arrival, seq, span=None):
         self.Xq = Xq
@@ -345,10 +362,17 @@ class _Request:
         self.parts: list = []
         self.lapsed = False
         self.span = span
+        self.q_clock = 0.0
+        self.behind_s = 0.0
 
     @property
     def sort_key(self):
         return (-self.priority, self.seq)
+
+    def leave_queue(self, clock: float):
+        """Charge the in-flight clock's advance since the request entered
+        the queue to its behind-slot wait."""
+        self.behind_s += clock - self.q_clock
 
 
 class Tenant:
@@ -388,7 +412,8 @@ class Tenant:
         self.oldest: float | None = None     # arrival of oldest pending
         # fault-tolerance state (also guarded by the scheduler's _lock)
         self.inflight = False         # a packed slot is inside predict_fn
-        self.inflight_since: float | None = None
+        self.inflight_since: float | None = None   # its pack start
+        self.inflight_s = 0.0         # finished slots' in-flight seconds
         self.inflight_riders: list | None = None
         self.quarantined = False      # watchdog benched this tenant
 
@@ -396,6 +421,13 @@ class Tenant:
 
     def _has_pending(self) -> bool:
         return self.pending_rows > 0
+
+    def _inflight_clock(self, now: float) -> float:
+        """Seconds during which one of this tenant's slots was in flight
+        (from its pack to the end of `_execute`), up to `now`."""
+        if self.inflight_since is None:
+            return self.inflight_s
+        return self.inflight_s + max(0.0, now - self.inflight_since)
 
     def _refresh_oldest(self):
         arrivals = [r.arrival for _, r in self.heap]
@@ -437,7 +469,7 @@ class ServingScheduler:
     `registry` (default: the process-wide `repro.obs.default_registry()`)
     receives the tenant-labeled counter/histogram mirror; `span_log` (a
     path or `repro.obs.SpanLog`) exports one JSONL event per finished
-    request with the per-stage span timings.
+    request with the per-stage span timings, and one per dispatched slot.
     """
 
     def __init__(self, *, max_wait_ms: float = 2.0, autostart: bool = True,
@@ -453,6 +485,7 @@ class ServingScheduler:
         self._order: list[str] = []
         self._rr = 0                      # round-robin cursor into _order
         self._seq = 0
+        self._slot_seq = 0
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)    # new work / close
         self._space = threading.Condition(self._lock)   # queue space freed
@@ -681,6 +714,7 @@ class ServingScheduler:
                            span=span)
             if span is not None:
                 span.labels["seq"] = req.seq
+                req.q_clock = t._inflight_clock(now)
             heapq.heappush(t.heap, (req.sort_key, req))
             t.pending_rows += req.n
             if t.oldest is None or now < t.oldest:
@@ -725,6 +759,8 @@ class ServingScheduler:
                     and req.off == 0):
                 if t.deadline_policy == "drop":
                     t.pending_rows -= req.n
+                    if req.span is not None:
+                        req.leave_queue(t.inflight_s)
                     dropped.append(req)
                     continue
                 if not req.lapsed:
@@ -749,6 +785,10 @@ class ServingScheduler:
             if req is None:
                 break
             take = min(req.n - req.off, slot - rows)
+            if req.span is not None:
+                # no slot of this tenant is in flight while it packs: the
+                # clock stands at inflight_s
+                req.leave_queue(t.inflight_s)
             riders.append((req, req.off, take))
             req.off += take
             rows += take
@@ -767,49 +807,83 @@ class ServingScheduler:
         """Pack and serve ONE slot for the next tenant in round-robin
         order. Returns True if a slot was dispatched. `force` dispatches
         partial slots immediately (drain / manual stepping)."""
-        now = time.perf_counter()
-        dropped: list[_Request] = []
-        with self._lock:
-            t = self._next_tenant_locked(now, force)
-            plan = None if t is None else self._pack_locked(t, now, dropped)
-            if plan is not None:
-                # mark in-flight UNDER the pack lock so the watchdog sees
-                # the dispatch the moment it can exist
-                t.inflight = True
-                t.inflight_since = time.perf_counter()
-                t.inflight_riders = list(plan[0])
-        # futures resolve OUTSIDE the lock: done-callbacks may re-enter
-        # (submit a follow-up request) without deadlocking
-        for req in dropped:
-            t.stats.count("dropped")
-            if req.span is not None:
-                req.span.advance("queue")
-                self._emit(req.span.event("deadline_dropped", rows=req.n))
-            if not req.fut.cancelled():
-                req.fut.set_exception(DeadlineExceeded(
-                    f"request missed its deadline by "
-                    f"{(now - req.deadline) * 1e3:.1f} ms before scheduling"))
-        if plan is None:
-            return False
-        self._execute(t, *plan, t_pack0=now)
+        with TraceAnnotation("scheduler/pack"):
+            # the slot's start on the perf_counter clock, in integer ns for
+            # its slot event (the same clock as every other reading here)
+            t_ns = time.perf_counter_ns()
+            now = t_ns / 1e9
+            dropped: list[_Request] = []
+            with self._lock:
+                t = self._next_tenant_locked(now, force)
+                plan = None if t is None \
+                    else self._pack_locked(t, now, dropped)
+                if plan is not None:
+                    # mark in-flight UNDER the pack lock so the watchdog
+                    # sees the dispatch the moment it can exist; its start
+                    # also starts the tenant's in-flight clock
+                    t.inflight = True
+                    t.inflight_since = now
+                    t.inflight_riders = list(plan[0])
+                    self._slot_seq += 1
+                    slot_id = self._slot_seq
+            # futures resolve OUTSIDE the lock: done-callbacks may re-enter
+            # (submit a follow-up request) without deadlocking
+            for req in dropped:
+                t.stats.count("dropped")
+                if req.span is not None:
+                    req.span.advance("queue")
+                    self._emit(self._request_event(req, "deadline_dropped"))
+                if not req.fut.cancelled():
+                    req.fut.set_exception(DeadlineExceeded(
+                        f"request missed its deadline by "
+                        f"{(now - req.deadline) * 1e3:.1f} ms before "
+                        f"scheduling"))
+            if plan is None:
+                return False
+            riders, slot = plan
+            try:
+                batch = self._assemble(riders, slot)
+            except BaseException:
+                self._release(t)
+                raise
+            t0 = time.perf_counter()
+        self._execute(t, riders, slot, slot_id, batch, t_ns, t0)
         return True
+
+    @staticmethod
+    def _assemble(riders, slot: int):
+        """The slot's (slot, D) query batch: the riders' rows in order,
+        edge-replicated to the slot size (pad rows are a served workload,
+        never X=0)."""
+        batch = np.concatenate([req.Xq[a:a + k] for req, a, k in riders],
+                               axis=0)
+        rows = batch.shape[0]
+        if rows < slot:
+            batch = np.concatenate(
+                [batch, np.repeat(batch[-1:], slot - rows, axis=0)])
+        return batch
 
     def _predict_slot(self, t: Tenant, batch, rows: int, retries: int):
         """Run one slot batch through predict_fn with retry-on-failure
         (exponential backoff). Returns host arrays (mean, var, t_disp,
-        t_dev); raises the LAST exception once retries are exhausted.
-        device->host transfer stays inside the guard: deferred runtime
-        errors surface here, failing the dispatch and not the worker."""
+        t_dev, t_fetch); raises the LAST exception once retries are
+        exhausted. device->host transfer stays inside the guard: deferred
+        runtime errors surface here, failing the dispatch and not the
+        worker."""
         attempt = 0
         while True:
             try:
-                out = t.predict_fn(jnp.asarray(batch))
-                mean, var = out[0], out[1]
+                with TraceAnnotation("scheduler/dispatch"):
+                    out = t.predict_fn(jnp.asarray(batch))
+                    mean, var = out[0], out[1]
                 t_disp = time.perf_counter()   # async dispatch returned
-                jax.block_until_ready(mean)
+                with TraceAnnotation("scheduler/device_wait"):
+                    jax.block_until_ready(mean)
                 t_dev = time.perf_counter()
-                return (np.asarray(mean)[:rows], np.asarray(var)[:rows],
-                        t_disp, t_dev)
+                with TraceAnnotation("scheduler/fetch"):
+                    mean = np.asarray(mean)[:rows]
+                    var = np.asarray(var)[:rows]
+                return mean, var, t_disp, t_dev, time.perf_counter()
             except Exception:
                 if attempt >= retries:
                     raise
@@ -817,22 +891,31 @@ class ServingScheduler:
                 time.sleep(t.retry_backoff_ms * (2.0 ** attempt) * 1e-3)
                 attempt += 1
 
+    @staticmethod
+    def _request_event(req: _Request, outcome: str, **extra) -> dict:
+        """A request's span event, with its queue time split by cause."""
+        queue = req.span.stages.get("queue", 0.0)
+        return req.span.event(outcome, rows=req.n, queue_split_ms={
+            "behind_slot": req.behind_s * 1e3,
+            "idle": (queue - req.behind_s) * 1e3}, **extra)
+
     def _fail_riders(self, t: Tenant, riders, exc):
         for req, _, _ in riders:
             if req.span is not None:
                 req.span.advance("stitch")
-                self._emit(req.span.event("error", rows=req.n))
+                self._emit(self._request_event(req, "error"))
             if not req.fut.done():     # done(): watchdog may have beaten us
                 req.fut.set_exception(exc)
 
-    def _deliver(self, t: Tenant, riders, mean, var, slot: int, dt: float):
+    def _deliver(self, t: Tenant, riders, mean, var, slot: int, slot_id: int,
+                 dt: float):
         """Fan a served slot's answers back out to its riders and account
         the dispatch (called WITHOUT the lock)."""
         rows = sum(k for _, _, k in riders)
         off = 0
         done = time.perf_counter()
         for req, _, k in riders:
-            req.parts.append((mean[off:off + k], var[off:off + k]))
+            req.parts.append((mean[off:off + k], var[off:off + k], slot_id))
             off += k
             if sum(p[0].shape[0] for p in req.parts) == req.n:
                 m = np.concatenate([p[0] for p in req.parts])
@@ -841,55 +924,54 @@ class ServingScheduler:
                     req.span.advance("stitch")
                     t.stats.record_latency(req.span.elapsed)
                     t.stats.record_stages(req.span.stages)
-                    self._emit(req.span.event(
-                        "ok", rows=req.n, slots=len(req.parts)))
+                    t.stats.record_queue_idle(
+                        req.span.stages["queue"] - req.behind_s)
+                    self._emit(self._request_event(
+                        req, "ok", slots=len(req.parts),
+                        slot_ids=[p[2] for p in req.parts]))
                 else:
                     t.stats.record_latency(done - req.arrival)
                 if not req.fut.done():
                     req.fut.set_result((m, v))
             elif req.span is not None:
                 req.span.advance("stitch")     # next slot waits in "queue"
+                # read without the lock: only this worker moves the clock
+                # while its slot is in flight
+                req.q_clock = t._inflight_clock(req.span.t_last)
         t.stats.count("queries", rows)
         t.stats.count("padded_queries", slot - rows)
         t.stats.count("batches")
         t.stats.add_engine_seconds(dt)
         t.stats.update_gauges()
 
-    def _isolate_riders(self, t: Tenant, riders, exc):
+    def _isolate_riders(self, t: Tenant, riders, exc, slot_id: int):
         """Per-rider failure isolation: the shared slot failed after
         retries, so re-run each rider ALONE (smallest fitting slot, single
         attempt). Healthy riders get answers; only the poisoned one(s)
         get the exception."""
         for rider in riders:
-            req, a, k = rider
-            sub = req.Xq[a:a + k]
+            k = rider[2]
             slot = next((s for s in t.slots if s >= k), t.slots[-1])
-            batch = sub if k == slot else np.concatenate(
-                [sub, np.repeat(sub[-1:], slot - k, axis=0)])
+            batch = self._assemble([rider], slot)
             t0 = time.perf_counter()
             try:
-                mean, var, _, t_dev = self._predict_slot(t, batch, k, 0)
+                mean, var, _, t_dev, _ = self._predict_slot(t, batch, k, 0)
             except Exception as sub_exc:
                 self._fail_riders(t, [rider], sub_exc)
             else:
                 t.stats.count("isolated")
-                self._deliver(t, [rider], mean, var, slot, t_dev - t0)
+                self._deliver(t, [rider], mean, var, slot, slot_id,
+                              t_dev - t0)
 
-    def _execute(self, t: Tenant, riders, slot: int, *,
-                 t_pack0: float | None = None):
-        """Run one packed slot through the tenant's predict_fn and fan the
-        answers back out (called WITHOUT the lock)."""
-        if t_pack0 is None:
-            t_pack0 = time.perf_counter()
+    def _execute(self, t: Tenant, riders, slot: int, slot_id: int, batch,
+                 t_ns: int, t0: float):
+        """Run one packed slot (`batch`, packed from `t_ns` to `t0`)
+        through the tenant's predict_fn and fan the answers back out
+        (called WITHOUT the lock). With a span log, the slot's event
+        records its stages, which tile its host wall time."""
+        t_pack0 = t_ns / 1e9
         try:
-            parts = [req.Xq[a:a + k] for req, a, k in riders]
             rows = sum(k for _, _, k in riders)
-            batch = np.concatenate(parts, axis=0)
-            if rows < slot:
-                # edge-replicate: pad rows are a served workload, never X=0
-                batch = np.concatenate(
-                    [batch, np.repeat(batch[-1:], slot - rows, axis=0)])
-            t0 = time.perf_counter()
             for req, _, _ in riders:
                 if req.span is not None:
                     # a multi-slot request re-enters "queue" after each
@@ -897,11 +979,11 @@ class ServingScheduler:
                     req.span.advance("queue", t_pack0)
                     req.span.advance("pack", t0)
             try:
-                mean, var, t_disp, t_dev = self._predict_slot(
+                mean, var, t_disp, t_dev, t_fetch = self._predict_slot(
                     t, batch, rows, t.retries)
             except Exception as exc:
                 if t.isolate and len(riders) > 1:
-                    self._isolate_riders(t, riders, exc)
+                    self._isolate_riders(t, riders, exc, slot_id)
                 else:
                     self._fail_riders(t, riders, exc)
                 return
@@ -909,17 +991,37 @@ class ServingScheduler:
                 if req.span is not None:
                     req.span.advance("dispatch", t_disp)
                     req.span.advance("device", t_dev)
-            self._deliver(t, riders, mean, var, slot, t_dev - t0)
+            with TraceAnnotation("scheduler/deliver"):
+                self._deliver(t, riders, mean, var, slot, slot_id,
+                              t_dev - t0)
+            if self.span_log is not None:
+                t_done = time.perf_counter()
+                self._emit({
+                    "event": "slot", "tenant": t.name, "slot_id": slot_id,
+                    "rows": rows, "slot": slot,
+                    "seqs": [req.seq for req, _, _ in riders],
+                    "t0_ns": t_ns,
+                    "slot_ms": {"pack": (t0 - t_pack0) * 1e3,
+                                "dispatch": (t_disp - t0) * 1e3,
+                                "device_wait": (t_dev - t_disp) * 1e3,
+                                "fetch": (t_fetch - t_dev) * 1e3,
+                                "deliver": (t_done - t_fetch) * 1e3}})
         finally:
-            with self._lock:
-                t.inflight = False
-                t.inflight_since = None
-                t.inflight_riders = None
-                if t.quarantined:
-                    # the stalled call came back (its riders were already
-                    # failed by the watchdog): the tenant can serve again
-                    t.quarantined = False
-                self._work.notify_all()
+            self._release(t)
+
+    def _release(self, t: Tenant):
+        """End the tenant's in-flight slot: stop its in-flight clock and
+        let the worker pack for it again."""
+        with self._lock:
+            t.inflight_s += time.perf_counter() - t.inflight_since
+            t.inflight = False
+            t.inflight_since = None
+            t.inflight_riders = None
+            if t.quarantined:
+                # the stalled call came back (its riders were already
+                # failed by the watchdog): the tenant can serve again
+                t.quarantined = False
+            self._work.notify_all()
 
     # -- worker / lifecycle --------------------------------------------------
 
@@ -945,11 +1047,10 @@ class ServingScheduler:
                         timeout = remaining if timeout is None \
                             else min(timeout, remaining)
                 if not ready:
+                    # look again before stepping: a step that finds nothing
+                    # to dispatch would leave an empty `scheduler/pack` span
                     self._work.wait(timeout=timeout)
-                    if self._closing:
-                        return
-                    if gen is not None and gen != self._worker_gen:
-                        return
+                    continue
             self.step()
 
     def _watchdog_loop(self):
@@ -981,6 +1082,10 @@ class ServingScheduler:
                         t.carry = None
                     queued += [r for _, r in t.heap]
                     queued += list(t.lapsed)
+                    clock = t._inflight_clock(now)
+                    for r in queued:
+                        if r.span is not None:
+                            r.leave_queue(clock)
                     t.heap.clear()
                     t.lapsed.clear()
                     t.pending_rows = 0
@@ -1003,7 +1108,7 @@ class ServingScheduler:
                 for req in queued:
                     if req.span is not None:
                         req.span.advance("queue")
-                        self._emit(req.span.event("stalled", rows=req.n))
+                        self._emit(self._request_event(req, "stalled"))
                     if not req.fut.done():
                         req.fut.set_exception(exc)
 

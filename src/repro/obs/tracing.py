@@ -9,8 +9,9 @@ docs/observability.md holds with zero slack). A request that streams
 across several slots re-enters "queue" after each slot's "stitch" — the
 inter-slot wait is queueing, and the accounting stays contiguous.
 
-`SpanLog` is the JSONL sink: one line per finished request (see
-docs/observability.md for the event schema), safe for concurrent emits.
+`SpanLog` is the JSONL sink: one line per finished request, and one per
+dispatched scheduler slot (`"event": "slot"`; see docs/observability.md
+for both schemas), safe for concurrent emits.
 """
 from __future__ import annotations
 
@@ -97,12 +98,17 @@ class SpanLog:
         self.close()
 
 
-def read_spans(path: str) -> list[dict]:
-    """Parse a SpanLog JSONL file back into event dicts (skips blanks)."""
+def read_spans(path: str, event: str | None = "request") -> list[dict]:
+    """Parse a SpanLog JSONL file back into event dicts (skips blanks).
+
+    Returns the events of kind `event` (request spans by default);
+    `event=None` returns every event, slot events included."""
     events = []
     with open(path) as fh:
         for line in fh:
             line = line.strip()
             if line:
-                events.append(json.loads(line))
+                ev = json.loads(line)
+                if event is None or ev.get("event") == event:
+                    events.append(ev)
     return events
