@@ -238,7 +238,7 @@ def streaming_phase(Xp, yp):
         L_ref, alpha_ref = chol_factors(fleet.log_theta, f.Xp, f.yp,
                                         cfg.jitter)
         L0 = L_ref[0]
-        up = ops.cholupdate(L0, L0[:, 0], shift=1, use_pallas=True)
+        up = ops.cholupdate_evict(L0, 1.0, use_pallas=True)
         up_ref = ref.cholupdate_ref(L0, L0[:, 0], shift=1)
         scale = float(jnp.max(jnp.abs(L_ref)))
         # f32 factors of a W=2048 window: incremental (3 evict/append

@@ -16,10 +16,17 @@ INCREMENTALLY, O(W^2) per event against O(W^3) for a refit:
       blocked triangular solves.
   evict_oldest(state, agent)   — drop slot 0: one rank-1 Cholesky UPDATE
       of the trailing (W-1)^2 block with the evicted point's sub-diagonal
-      column (kernels.ops.cholupdate — the O(W^2) column sweep), with the
-      one-slot shift fused into the write. Age order makes the evicted row
-      STATICALLY slot 0, so the sweep runs over static slices and its
-      panel skip kicks in for partially filled windows.
+      column (kernels.ops.cholupdate_evict — the O(W^2) column sweep).
+      Age order makes the evicted row STATICALLY slot 0, so the one-slot
+      shift is written by the sweep itself: on the jnp path each panel's
+      write lands one slot up-left (and its panel skip kicks in for
+      partially filled windows); on the TPU the Pallas kernel writes each
+      column one slot up-left and the fresh sentinel row and column, so
+      the evict makes one pass over the factor into the kernel's
+      transposed layout and one pass out, nothing else. Vmapped over a
+      fleet (`observe_fleet`), each agent's "window full" flag goes into
+      the kernel, which copies a filling window's factor through
+      unchanged: no select over the factors.
 
 Fixed shapes make every operation jit-able with a traced `agent` index:
 empty slots are *sentinel observations* — pseudo-inputs placed
@@ -44,12 +51,13 @@ uses the same slot order, so incremental factors are directly comparable
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from ...kernels.ops import cholupdate
+from ...kernels.ops import cholupdate_evict
 from ..gp.kernel import se_kernel, unpack
 
 _SENTINEL = 1e6
@@ -81,6 +89,20 @@ def _fwd_solve(L, b):
         k1 = min(k0 + _SOLVE_BK, n)
         rhs = b[k0:k1] - L[k0:k1, :k0] @ sol[:k0]
         s = jax.scipy.linalg.solve_triangular(L[k0:k1, k0:k1], rhs,
+                                              lower=True)
+        sol = sol.at[k0:k1].set(s)
+    return sol
+
+
+def _fwd_solve_t(U, b):
+    """Forward substitution L sol = b given U = L^T: the same panels, the
+    products in vector-matrix form over U's rows."""
+    n = U.shape[0]
+    sol = jnp.zeros_like(b)
+    for k0 in range(0, n, _SOLVE_BK):
+        k1 = min(k0 + _SOLVE_BK, n)
+        rhs = b[k0:k1] - sol[:k0] @ U[:k0, k0:k1]
+        s = jax.scipy.linalg.solve_triangular(U[k0:k1, k0:k1].T, rhs,
                                               lower=True)
         sol = sol.at[k0:k1].set(s)
     return sol
@@ -203,24 +225,21 @@ def from_batch(log_theta, Xp, yp, window: int | None = None,
 
 # -- per-agent incremental cores (vmap-able) --------------------------------
 
-def _evict_oldest_shift(log_theta, jitter, Xw, yw, L):
+def _evict_oldest_shift(log_theta, jitter, Xw, yw, L, evict=True):
     """Drop slot 0: the remaining points' factor is the rank-1 UPDATE of
     the trailing block with the evicted sub-diagonal column (the factor
     mass column 0 carried), written one slot up-left; slot W-1 becomes a
     fresh sentinel at `last coordinate + _SENTINEL` (keeps all sentinel
     coordinates pairwise _SENTINEL-separated — after a full-window evict
     it is the ONLY sentinel, otherwise it extends the monotone sentinel
-    tail). A window that is already empty only rotates its sentinels."""
-    W, D = Xw.shape
-    # rank-1 update of the trailing block with the evicted sub-diagonal
-    # column, written one slot up-left in the same sweep (shift=1); the
-    # stale last row/column becomes the fresh sentinel
-    L = cholupdate(L, L[:, 0], shift=1)
-    evec = _s_diag(log_theta, jitter) * (jnp.arange(W) == W - 1)
-    L = L.at[W - 1].set(evec).at[:, W - 1].set(evec)
-    Xw = jnp.concatenate([Xw[1:], Xw[W - 1:] + _SENTINEL])
-    yw = jnp.concatenate([yw[1:], jnp.zeros((1,), yw.dtype)])
-    return Xw, yw, L
+    tail). A window that is already empty only rotates its sentinels.
+    Where `evict` (traced is fine) is False, the window comes back as it
+    went in: the factor through the kernel's own pass-through (see
+    kernels.ops.cholupdate_evict), the small arrays through a select."""
+    L = cholupdate_evict(L, _s_diag(log_theta, jitter), evict)
+    Xs = jnp.concatenate([Xw[1:], Xw[-1:] + _SENTINEL])
+    ys = jnp.concatenate([yw[1:], jnp.zeros((1,), yw.dtype)])
+    return jnp.where(evict, Xs, Xw), jnp.where(evict, ys, yw), L
 
 
 def _append_one(log_theta, jitter, Xw, yw, L, slot, x, y):
@@ -233,7 +252,9 @@ def _append_one(log_theta, jitter, Xw, yw, L, slot, x, y):
     x = x.astype(Xw.dtype)
     kvec = se_kernel(Xw, x[None], log_theta)[:, 0]          # sentinels -> 0.0
     c1 = jnp.where(idx < slot, kvec, 0.0)
-    w = jnp.where(idx < slot, _fwd_solve(L, c1), 0.0)
+    # the solve reads L^T: on the TPU that is the evict kernel's own
+    # output, so no copy of the factor in a second layout is made for it
+    w = jnp.where(idx < slot, _fwd_solve_t(L.T, c1), 0.0)
     d2 = sigma_f**2 + sigma_eps**2 + jitter - jnp.sum(w * w)
     d = jnp.sqrt(jnp.maximum(d2, jnp.finfo(Xw.dtype).tiny))
     L = L.at[slot].set(w + d * (idx == slot))   # row: (w_{<slot}, d, 0...)
@@ -242,15 +263,20 @@ def _append_one(log_theta, jitter, Xw, yw, L, slot, x, y):
     return Xw, yw, L
 
 
-def _observe_one(log_theta, jitter, Xw, yw, L, count, x, y):
-    # device scopes: the evict scope holds the cond too (vmapped, it becomes
-    # a select over the whole factor)
+def _observe_one(log_theta, jitter, Xw, yw, L, count, x, y, vmapped=False):
+    # device scopes: online/evict holds the evict's passes over the factor
     full = count >= Xw.shape[0]
     with jax.named_scope("online/evict"):
-        Xw, yw, L = jax.lax.cond(
-            full,
-            lambda a: _evict_oldest_shift(log_theta, jitter, *a),
-            lambda a: a, (Xw, yw, L))
+        if vmapped:
+            # a cond would become a select over every factor: the evict
+            # takes the per-agent flag instead
+            Xw, yw, L = _evict_oldest_shift(log_theta, jitter, Xw, yw, L,
+                                            full)
+        else:
+            Xw, yw, L = jax.lax.cond(
+                full,
+                lambda a: _evict_oldest_shift(log_theta, jitter, *a),
+                lambda a: a, (Xw, yw, L))
     count = jnp.where(full, count - 1, count)
     with jax.named_scope("online/append"):
         Xw, yw, L = _append_one(log_theta, jitter, Xw, yw, L, count, x, y)
@@ -293,7 +319,8 @@ def observe_fleet(state: OnlineExperts, xs, ys) -> OnlineExperts:
     """Every agent ingests one observation (xs (M, D), ys (M,)) — the
     vmapped hot path for synchronous streams."""
     Xw, yw, L, alpha, count = jax.vmap(
-        _observe_one, in_axes=(None, None, 0, 0, 0, 0, 0, 0))(
+        functools.partial(_observe_one, vmapped=True),
+        in_axes=(None, None, 0, 0, 0, 0, 0, 0))(
             state.log_theta, state.jitter, state.Xw, state.yw, state.L,
             state.count, xs, ys)
     return state._replace(Xw=Xw, yw=yw, L=L, alpha=alpha, count=count)

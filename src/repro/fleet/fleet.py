@@ -21,6 +21,8 @@ facade adds dispatch and state management, never new numerics):
                config.routed) — compiled programs cached per method
   observe()    `core.online` sliding-window experts: O(W^2) rank-1 factor
                updates hot-swapped into the engine, zero recompiles
+               (`evict_oldest()` drops one agent's oldest point; both
+               count `gp_online_evictions_total`)
   join()/leave()  dynamic membership: window state + consensus graph +
                engine rewire in one step
   shard()      move a fitted fleet onto the agent-sharded engine in place
@@ -46,6 +48,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..checkpoint.io import restore, save_checkpoint
 from ..obs import default_registry
@@ -53,8 +56,8 @@ from ..core.consensus import (complete_graph, connected_components,
                               cycle_graph, is_connected, path_graph,
                               random_connected_graph)
 from ..core.gp import augment, communication_dataset, pack
-from ..core.online import (OnlineExperts, from_batch, join, leave,
-                           observe_fleet, refit)
+from ..core.online import (OnlineExperts, evict_oldest, from_batch, join,
+                           leave, observe_fleet, refit)
 from ..core.prediction import (FittedExperts, PredictionEngine, ShardedEngine,
                                fit_experts)
 from ..core.sparse import (SparseExperts, fit_sparse_experts,
@@ -119,9 +122,13 @@ class GPFleet:
         self.fitted_aug: FittedExperts | None = None
         self.fitted_comm: FittedExperts | None = None
         self._online_state: OnlineExperts | None = None
+        # host mirror of the window counts: the evictions are counted on
+        # the host, with no device read on the event path
+        self._counts_host: np.ndarray | None = None
         self._comm_data = None         # (Xc, yc, Xa, ya) when built
         self._engine = None
         self._ingest = None
+        self._evict = None
         self._last_degraded = None     # census of the last degraded predict
 
     # -- properties ----------------------------------------------------------
@@ -236,6 +243,7 @@ class GPFleet:
         if cfg.online:
             self._online_state = from_batch(lt, Xp, yp, window=cfg.window,
                                             jitter=cfg.jitter)
+            self._counts_host = np.array(self._online_state.count)
             self.fitted = self._online_state.to_fitted()
         elif cfg.sparse_m is not None:
             # the fact-sparse trainer jointly optimized the inducing sets;
@@ -459,15 +467,48 @@ class GPFleet:
     def observe(self, xs, ys) -> "GPFleet":
         """Ingest one observation per agent (xs (M, D), ys (M,)) through the
         O(W^2) rank-1 factor updates and hot-swap the engine's served
-        factors — zero recompiles. Returns self."""
+        factors — zero recompiles. Every agent whose window is full evicts
+        its oldest point first (counted in `gp_online_evictions_total`).
+        Returns self."""
         state = self._require_online("observe")
         if self._ingest is None:
             self._ingest = jax.jit(observe_fleet)
         self._online_state = self._ingest(state, xs, ys)
+        self._count_evictions(int(np.sum(self._counts_host >= state.window)))
+        self._counts_host = np.minimum(self._counts_host + 1, state.window)
+        self._swap_online()
+        return self
+
+    def evict_oldest(self, agent: int) -> "GPFleet":
+        """Agent `agent` drops its oldest observation (a no-op on an empty
+        window) and the engine's served factors are hot-swapped. Returns
+        self."""
+        state = self._require_online("evict_oldest")
+        agent = int(agent)
+        if not 0 <= agent < state.num_agents:
+            raise ValueError(f"agent {agent} not in fleet of "
+                             f"{state.num_agents}")
+        if self._evict is None:
+            self._evict = jax.jit(evict_oldest)
+        self._online_state = self._evict(state, agent)
+        if self._counts_host[agent] > 0:
+            self._count_evictions(1)
+            self._counts_host[agent] -= 1
+        self._swap_online()
+        return self
+
+    @staticmethod
+    def _count_evictions(n: int):
+        if n:
+            default_registry().counter(
+                "gp_online_evictions_total",
+                "agent-events that evicted a window's oldest point "
+                "(observe on a full window, evict_oldest)").inc(n)
+
+    def _swap_online(self):
         self.fitted = self._online_state.to_fitted()
         if self._engine is not None:
             self._engine.swap_experts(self.fitted)
-        return self
 
     def drift(self, *, grad_fn=None, iters: int | None = None) -> dict:
         """Re-run the configured decentralized trainer on the LIVE sliding
@@ -484,7 +525,7 @@ class GPFleet:
         never retraces across a drift epoch. Returns the trainer info dict.
         """
         state = self._require_online("drift")
-        n = int(jnp.min(state.count))
+        n = int(np.min(self._counts_host))
         if n < 2:
             raise RuntimeError(
                 f"drift needs >= 2 observations in every agent's window "
@@ -504,9 +545,7 @@ class GPFleet:
             grad_fn=grad_fn)
         self._online_state = refit(state._replace(
             log_theta=self.log_theta.astype(state.log_theta.dtype)))
-        self.fitted = self._online_state.to_fitted()
-        if self._engine is not None:
-            self._engine.swap_experts(self.fitted)
+        self._swap_online()             # the window counts are unchanged
         return info
 
     def join(self, X_new=None, y_new=None, neighbors=None) -> "GPFleet":
@@ -519,6 +558,8 @@ class GPFleet:
                              "construction)")
         self._online_state, self.A = join(state, self.A, X_new, y_new,
                                           neighbors=neighbors)
+        n_new = 0 if X_new is None else min(len(X_new), state.window)
+        self._counts_host = np.append(self._counts_host, n_new)
         self._after_membership_change()
         return self
 
@@ -531,6 +572,7 @@ class GPFleet:
                              "engine (ShardedEngine shards are fixed at "
                              "construction)")
         self._online_state, self.A = leave(state, self.A, agent)
+        self._counts_host = np.delete(self._counts_host, int(agent))
         self._after_membership_change()
         return self
 
@@ -681,6 +723,7 @@ class GPFleet:
             fleet._online_state = OnlineExperts(
                 f.log_theta, f.Xp, f.yp, f.L, f.alpha, tree["count"],
                 tree["jitter"])
+            fleet._counts_host = np.array(tree["count"])
         if (get_method(cfg.method).needs_augmented_data
                 and fleet.fitted_aug is None):
             raise ValueError(

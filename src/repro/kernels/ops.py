@@ -10,7 +10,8 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .cholupdate import cholupdate_pallas, panel_rows
+from .cholupdate import (cholupdate_evict_pallas, cholupdate_pallas,
+                         panel_rows)
 from .nll_grad import nll_grad_pallas
 from .rbf_gram import rbf_gram_pallas
 from .rbf_matvec import rbf_matvec_pallas
@@ -201,48 +202,72 @@ def nll_grad_fused(log_theta, d2u, inner, K=None, use_pallas: bool | None = None
                             (sigma_eps**2 * sums[D + 1:D + 2])])
 
 
+def _panel(n: int, bk: int) -> int:
+    """Panel height of the Pallas sweeps: the kernels double-buffer
+    (bk, n) panels in VMEM, so the height is capped to their budget (the
+    jnp path takes `bk` as given); a multiple of 8, or n itself."""
+    return min(n, max(8, min(bk, panel_rows(n + 128), n) // 8 * 8))
+
+
 @partial(jax.jit, static_argnames=("downdate", "use_pallas", "interpret",
-                                   "bk", "shift"))
+                                   "bk"))
 def cholupdate(L, x, downdate: bool = False, use_pallas: bool | None = None,
-               interpret: bool | None = None, bk: int = 256,
-               shift: int = 0):
+               interpret: bool | None = None, bk: int = 256):
     """Rank-1 Cholesky update/downdate chol(L L^T +/- x x^T) — O(n^2).
 
-    L (n, n) lower-triangular, x (n,). Padded columns get a unit diagonal
-    and a zero x entry, which the column sweep provably leaves untouched,
-    so tile alignment never perturbs the factor. The pure-jnp path keeps
-    the input dtype (float64-safe); the Pallas path COMPUTES in float32
-    like the other TPU kernels but casts back to L.dtype — callers that
-    persist the factor in a pytree (core/online) rely on the dtype being
-    preserved.
-
-    `shift=k` (static) updates the trailing block L[k:, k:] with x[k:] and
-    returns it moved k slots up-left (fused on the jnp path; the trailing
-    k rows/cols of the result are stale — see ref.cholupdate_ref).
+    L (n, n) lower-triangular, x (n,). The pure-jnp path keeps the input
+    dtype (float64-safe); the Pallas path COMPUTES in float32 like the
+    other TPU kernels but casts back to L.dtype — callers that persist the
+    factor in a pytree (core/online) rely on the dtype being preserved.
+    The Pallas path's passes over the factor are the transposes into and
+    out of the kernel's layout (L^T; no padding: the grid takes a partial
+    last panel).
     """
     if use_pallas is None:
         use_pallas = _on_tpu()
     if not use_pallas:
-        return ref.cholupdate_ref(L, x, downdate, bk, shift)
+        return ref.cholupdate_ref(L, x, downdate, bk)
     if interpret is None:
         interpret = not _on_tpu()
-    if shift:
-        # Pallas path: update the trailing block, then one block move
-        # (HBM bandwidth makes the extra copy cheap on TPU)
-        n = L.shape[0]
-        sub = cholupdate(L[shift:, shift:], x[shift:], downdate,
-                         use_pallas, interpret, bk)
-        return L.at[:n - shift, :n - shift].set(sub.astype(L.dtype))
-    n = L.shape[0]
-    # the Pallas kernel double-buffers (bk, n) panels in VMEM — cap the
-    # panel height to its budget (the jnp path takes `bk` as given)
-    bk_ = max(8, min(bk, panel_rows(n + 128), n) // 8 * 8)
-    pad = (-n) % bk_
-    Lp = jnp.pad(L.astype(jnp.float32), ((0, pad), (0, pad)))
-    if pad:
-        tail = jnp.arange(n, n + pad)
-        Lp = Lp.at[tail, tail].set(1.0)
-    xp = _pad_to(x.astype(jnp.float32), bk_, 0)
     sign = -1.0 if downdate else 1.0
-    out = cholupdate_pallas(Lp, xp, sign, bk=bk_, interpret=interpret)
-    return out[:n, :n].astype(L.dtype)
+    U = cholupdate_pallas(L.T.astype(jnp.float32), x, sign,
+                          bk=_panel(L.shape[0], bk), interpret=interpret)
+    return U.T.astype(L.dtype)
+
+
+@partial(jax.jit, static_argnames=("use_pallas", "interpret", "bk"))
+def cholupdate_evict(L, fill, evict=True, use_pallas: bool | None = None,
+                     interpret: bool | None = None, bk: int = 256):
+    """Drop slot 0 of a factor: L (n, n) lower with L L^T = A in, out the
+    factor of A[1:, 1:] moved one slot up-left, with slot n-1 a fresh
+    slot whose row and column are e_{n-1} * fill — O(n^2), one rank-1
+    update of the trailing block with the evicted column L[1:, 0].
+    Where `evict` (a bool, traced is fine) is False, L comes back as it
+    went in. The result keeps L.dtype.
+
+    jnp path: ref.cholupdate_ref(shift=1), whose sweep writes the shift;
+    the fresh slot is written over the stale last row and column, and
+    `evict` selects between the result and L.
+
+    Pallas path: one pass in, the sweep, one pass out. In: L^T, the
+    kernel's layout. The kernel sweeps the whole factor from column 1
+    (column 0 is skipped exactly, not rotated by a zero), writes each
+    column one slot up-left, writes the fresh slot, and copies a factor
+    whose `evict` is False through unchanged. Out: the transpose back. So
+    the shift is written inside the kernel, and a vmapped fleet with
+    windows both full and not is served by the same two passes, with no
+    select over the factors.
+    """
+    if use_pallas is None:
+        use_pallas = _on_tpu()
+    n = L.shape[0]
+    if not use_pallas or n < 2:
+        out = ref.cholupdate_ref(L, L[:, 0], bk=bk, shift=1)
+        evec = (fill * (jnp.arange(n) == n - 1)).astype(L.dtype)
+        out = out.at[n - 1].set(evec).at[:, n - 1].set(evec)
+        return jnp.where(evict, out, L)
+    if interpret is None:
+        interpret = not _on_tpu()
+    U = cholupdate_evict_pallas(L.T.astype(jnp.float32), fill, evict,
+                                bk=_panel(n, bk), interpret=interpret)
+    return U.T.astype(L.dtype)

@@ -81,11 +81,42 @@ def test_cholupdate_zero_vector_is_noop_and_shift_evicts():
     L = jnp.asarray(np.linalg.cholesky(A))
     noop = ops.cholupdate(L, jnp.zeros(n))
     np.testing.assert_allclose(np.asarray(noop), np.asarray(L), atol=0)
-    # shift=1: evict the first point, result moved up-left in the sweep
-    out = ops.cholupdate(L, L[:, 0], shift=1)
+    # evict: drop the first point, the result moved up-left, the last slot
+    # a fresh sentinel
+    out = ops.cholupdate_evict(L, 3.0)
     np.testing.assert_allclose(np.asarray(out)[:n - 1, :n - 1],
                                np.linalg.cholesky(np.asarray(A)[1:, 1:]),
                                atol=1e-8)
+    evec = 3.0 * (np.arange(n) == n - 1)
+    assert np.array_equal(np.asarray(out)[n - 1], evec)
+    assert np.array_equal(np.asarray(out)[:, n - 1], evec)
+
+
+@pytest.mark.parametrize("n", [16, 20, 37])
+def test_cholupdate_evict_pallas_skips_column_zero_exactly(n):
+    """The evict kernel (interpret mode) at windows that fill their last
+    panel (16 rows) or leave it ragged: its sweep starts at column 1, so
+    the result is bit for bit the plain kernel's update of the trailing
+    block with x = L[1:, 0]; the last row and column are the fresh slot;
+    a False flag hands the factor back untouched."""
+    rng = np.random.default_rng(n)
+    B = rng.standard_normal((n, n))
+    A = B @ B.T + n * np.eye(n)
+    L = jnp.asarray(np.linalg.cholesky(A), jnp.float32)
+    out = np.asarray(ops.cholupdate_evict(L, 2.5, use_pallas=True,
+                                          interpret=True, bk=16))
+    trailing = ops.cholupdate(L[1:, 1:], L[1:, 0], use_pallas=True,
+                              interpret=True, bk=16)
+    assert np.array_equal(out[:n - 1, :n - 1], np.asarray(trailing))
+    np.testing.assert_allclose(out[:n - 1, :n - 1],
+                               np.linalg.cholesky(A[1:, 1:]), rtol=1e-5,
+                               atol=1e-5)
+    evec = np.where(np.arange(n) == n - 1, np.float32(2.5), 0.0)
+    assert np.array_equal(out[n - 1], evec)
+    assert np.array_equal(out[:, n - 1], evec)
+    keep = ops.cholupdate_evict(L, 2.5, jnp.asarray(False), use_pallas=True,
+                                interpret=True, bk=16)
+    assert np.array_equal(np.asarray(keep), np.asarray(L))
 
 
 # ---------------------------------------------------------------------------
@@ -138,6 +169,103 @@ def test_observe_fleet_matches_refit():
     ref_state = refit(state)
     np.testing.assert_allclose(np.asarray(state.L), np.asarray(ref_state.L),
                                atol=1e-6)
+
+
+WP = 20      # ragged against the evict kernel's 16-row panels at this width
+
+
+def _mixed_windows():
+    """float32 fleet of M windows of WP slots: agents 0 and 1 full, agent
+    2 filling, agent 3 empty; factors from scratch."""
+    rng = np.random.default_rng(11)
+    state = init_online(TRUE_LT.astype(jnp.float32), M, WP, D,
+                        dtype=jnp.float32)
+    counts = np.array([WP, WP, 7, 0])
+    valid = np.arange(WP)[None, :] < counts[:, None]
+    Xw = np.where(valid[..., None], rng.uniform(0.0, 1.0, (M, WP, D)),
+                  np.asarray(state.Xw))
+    yw = np.where(valid, rng.standard_normal((M, WP)), 0.0)
+    return refit(state._replace(
+        Xw=jnp.asarray(Xw, jnp.float32), yw=jnp.asarray(yw, jnp.float32),
+        count=jnp.asarray(counts, jnp.int32)))
+
+
+@pytest.fixture
+def pallas_evict(monkeypatch):
+    """Route core.online's evict through the Pallas kernel in interpret
+    mode (callers trace fresh functions, so no cached jnp trace is
+    reused)."""
+    import functools
+
+    from repro.core.online import experts
+    monkeypatch.setattr(experts, "cholupdate_evict", functools.partial(
+        ops.cholupdate_evict, use_pallas=True, interpret=True))
+
+
+def _observe_events(state, events=3):
+    rng = np.random.default_rng(5)
+    ingest = jax.jit(lambda s, x, y: observe_fleet(s, x, y))
+    for _ in range(events):
+        state = ingest(state,
+                       jnp.asarray(rng.uniform(0.0, 1.0, (M, D)),
+                                   jnp.float32),
+                       jnp.asarray(rng.standard_normal(M), jnp.float32))
+    return state
+
+
+def _assert_same_window(got, want, tol):
+    for field in ("Xw", "yw", "count"):
+        assert np.array_equal(np.asarray(getattr(got, field)),
+                              np.asarray(getattr(want, field))), field
+    np.testing.assert_allclose(np.asarray(got.L), np.asarray(want.L),
+                               atol=tol)
+    # alpha = C^-1 y reaches 1 / sigma_eps^2 = 100: compare relative to it
+    want_alpha = np.asarray(want.alpha)
+    np.testing.assert_allclose(np.asarray(got.alpha), want_alpha,
+                               atol=tol * np.max(np.abs(want_alpha)))
+
+
+def test_observe_fleet_pallas_interpret_mixed_windows(request):
+    """observe_fleet through the evict kernel (interpret mode) on a fleet
+    with full and filling windows at a ragged width: the full windows
+    slide, the others only append, and the factors match the jnp path
+    and a refit."""
+    state = _mixed_windows()
+    jnp_out = _observe_events(state)
+    request.getfixturevalue("pallas_evict")
+    pallas_out = _observe_events(state)
+    assert np.asarray(pallas_out.count).tolist() == [WP, WP, 10, 3]
+    _assert_same_window(pallas_out, jnp_out, 1e-5)
+    _assert_same_window(pallas_out, refit(pallas_out), 1e-4)
+    # the full windows hold the last WP points; sentinels follow the rest
+    assert np.all(np.abs(np.asarray(pallas_out.Xw[2, 10:])) >= 1e6)
+
+
+def test_observe_and_evict_oldest_pallas_interpret(request):
+    """Single-agent observe / evict_oldest (a real cond, traced agent
+    index) through the evict kernel match the jnp path and a refit: a
+    full window slides, a filling one appends, an empty one is left
+    alone."""
+    state = _mixed_windows()
+    x, y = jnp.asarray([0.3, 0.6], jnp.float32), jnp.asarray(0.4, jnp.float32)
+
+    def events(st):
+        obs = jax.jit(lambda s, a: observe(s, a, x, y))
+        ev = jax.jit(lambda s, a: evict_oldest(s, a))
+        st = obs(st, 0)            # full: evict + append
+        st = obs(st, 2)            # filling: append only
+        st = ev(st, 1)             # full -> WP - 1
+        st = ev(st, 3)             # empty: no-op
+        return ev(st, 2)
+
+    jnp_out = events(state)
+    request.getfixturevalue("pallas_evict")
+    pallas_out = events(state)
+    assert np.asarray(pallas_out.count).tolist() == [WP, WP - 1, 7, 0]
+    _assert_same_window(pallas_out, jnp_out, 1e-5)
+    _assert_same_window(pallas_out, refit(pallas_out), 1e-4)
+    np.testing.assert_array_equal(np.asarray(pallas_out.L[3]),
+                                  np.asarray(state.L[3]))
 
 
 def test_evict_on_empty_window_is_noop():
@@ -420,6 +548,63 @@ def test_randomized_membership_interleaving_stays_healthy(seed):
     assert np.isfinite(np.asarray(mean)).all()
     h = fleet.health()
     assert h["graph_connected"] and h["graph_components"] == 1
+
+
+def test_online_evictions_counter_tracks_window_counts():
+    """gp_online_evictions_total counts, on the host, every agent-event
+    that evicted: an observe on a full window, an evict_oldest on a
+    non-empty one, across filling, full, join, leave and evict events. It
+    equals what the device's window counts imply, and the fleet's mirror
+    of the counts stays equal to them."""
+    from repro.obs import default_registry
+
+    counter = default_registry().counter("gp_online_evictions_total")
+    v0 = counter.value()
+    rng = np.random.default_rng(4)
+    cfg = FleetConfig(num_agents=3, input_dim=2, online=True, window=4,
+                      chunk=4, dac_iters=30, method="rbcm",
+                      theta0=(0.8, 0.8, 1.0, 0.2))
+    fleet = GPFleet(cfg).fit(rng.uniform(0.0, 1.0, (3, 2, 2)),
+                             rng.standard_normal((3, 2)), train=False)
+    expected = 0
+
+    def counts():
+        return np.asarray(fleet.window_counts)
+
+    def observe_all():
+        nonlocal expected
+        expected += int(np.sum(counts() >= 4))
+        m = fleet.num_agents
+        fleet.observe(rng.uniform(0.0, 1.0, (m, 2)), rng.standard_normal(m))
+
+    def evict(agent):
+        nonlocal expected
+        expected += int(counts()[agent] > 0)
+        fleet.evict_oldest(agent)
+
+    for _ in range(4):                  # 2 -> 4 slots: filling, then full
+        observe_all()
+    fleet.join(rng.uniform(0.0, 1.0, (6, 2)), rng.standard_normal(6))
+    fleet.join()                        # an empty joiner warms up
+    observe_all()
+    fleet.leave(1)                      # counts (4, 4, 4, 1)
+    evict(0)
+    evict(3)
+    for _ in range(4):
+        evict(2)
+    evict(2)                            # empty by now: not an eviction
+    observe_all()                       # counts (4, 4, 1, 1)
+    # 0 + 0 + 3 + 3 while filling, 4 after the joins, 1 + 1 + 4 evicts,
+    # 1 from the last observe (agent 1's window was still full)
+    assert expected == 17
+    assert np.array_equal(fleet._counts_host, counts())
+    assert counter.value() - v0 == expected
+    observe_all()                       # counts (4, 4, 2, 2): drift-ready
+    fleet.drift(iters=2)
+    observe_all()
+    assert expected == 17 + 2 + 2
+    assert np.array_equal(fleet._counts_host, counts())
+    assert counter.value() - v0 == expected
 
 
 def test_save_load_mid_stream_is_bitwise(tmp_path):

@@ -10,7 +10,9 @@ the Pallas path — not the jnp reference — is what was compiled.
 Only the fixture below describes the topology: the TPU runtime may be
 loaded by one process at a time, so nothing here touches it at import.
 """
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,6 +112,103 @@ def test_cholupdate_evict_vmapped_over_agents(one_chip, no_cache, W):
     M = 16
 
     def evict(L):
-        return jax.vmap(lambda Li: ops.cholupdate(
-            Li, Li[:, 0], shift=1, use_pallas=True, interpret=False))(L)
+        return jax.vmap(lambda Li: ops.cholupdate_evict(
+            Li, 1.0, use_pallas=True, interpret=False))(L)
     _assert_kernel(_compile(evict, one_chip, (M, W, W)))
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\(([^)]*)\)")
+_COMPUTATION = re.compile(r"^%([\w.\-]+) \((.*)\) -> .* \{$")
+
+
+def _dims(s):
+    return tuple(int(d) for d in s.split(",") if d)
+
+
+def _factor_instructions(hlo, M, W):
+    """The optimized entry computation's instructions that produce a whole
+    (M, >= W-1, >= W-1) batch of factors, as (kernels, in-place updates,
+    passes). An in-place update is a dynamic-update-slice or scatter of a
+    small update, or a fusion rooted in one whose only whole-factor
+    operand is the buffer it updates; every other instruction reads and
+    writes whole factors."""
+    shapes, roots, params, entry = {}, {}, {}, []
+    comp = None
+    for line in hlo.splitlines():
+        if line.startswith("ENTRY"):
+            comp = "ENTRY"
+            continue
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            params[comp] = [_dims(p) for p in
+                            re.findall(r"\w+\[([\d,]*)\]", m.group(2))]
+            continue
+        if line.startswith("}"):
+            comp = None
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, dims, op, args = m.groups()
+        shapes[name] = _dims(dims)
+        if line.lstrip().startswith("ROOT"):
+            roots[comp] = op
+        if comp == "ENTRY":
+            entry.append((name, _dims(dims), op, args, line))
+
+    def whole(d):
+        return len(d) == 3 and d[0] == M and min(d[1:]) >= W - 1
+
+    def small(d):
+        return int(jnp.prod(jnp.asarray(d))) <= M * (W + 8) * 8
+
+    kernels, in_place, passes = [], [], []
+    for name, dims, op, args, line in entry:
+        if not whole(dims) or op in ("parameter", "bitcast", "copy-done",
+                                     "get-tuple-element", "tuple"):
+            continue
+        operands = re.findall(r"%([\w.\-]+)", args)
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        if op == "custom-call" and "tpu_custom_call" in line:
+            kernels.append(name)
+        elif op in ("dynamic-update-slice", "scatter") and small(
+                shapes[operands[-1 if op == "scatter" else 1]]):
+            in_place.append(name)
+        elif (op == "fusion" and roots.get(called.group(1)) in
+              ("dynamic-update-slice", "scatter")
+              and sum(map(whole, params[called.group(1)])) == 1):
+            in_place.append(name)
+        else:
+            passes.append(name)
+    return kernels, in_place, passes
+
+
+def test_observe_fleet_evict_makes_one_pass_in_and_one_out(
+        one_chip, no_cache, monkeypatch):
+    """The streaming cell's observe program (M=4 windows of W=8,100, D=2;
+    the Pallas evict forced on) holds, at the whole-factor batch shape,
+    the evict kernel, one pass into its transposed layout, one pass out,
+    and in-place row writes (the append) — no slice, pad, select or
+    second-layout copy of the factors around the sweep."""
+    from repro.core.online import OnlineExperts, experts, observe_fleet
+
+    monkeypatch.setattr(experts, "cholupdate_evict", functools.partial(
+        ops.cholupdate_evict, use_pallas=True, interpret=False))
+    M, W, D = 4, 8100, 2
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = OnlineExperts(spec((D + 2,)), spec((M, W, D)), spec((M, W)),
+                          spec((M, W, W)), spec((M, W)),
+                          spec((M,), jnp.int32), spec(()))
+    with jax.enable_x64(False):
+        compiled = jax.jit(lambda s, x, y: observe_fleet(s, x, y)).lower(
+            state, spec((M, D)), spec((M,))).compile()
+    kernels, in_place, passes = _factor_instructions(compiled.as_text(), M,
+                                                     W)
+    assert len(kernels) == 1 and "cholupdate" in kernels[0], kernels
+    assert len(passes) <= 2, passes
+    assert in_place, "the append's row write is no longer in place"
